@@ -43,6 +43,9 @@ def main(argv=None) -> None:
         tel = tele.Telemetry(record_spans=True, max_spans=2_000_000)
         tele.set_default(tel)
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     header()
     import benchmarks.bench_label_balance as b1
     import benchmarks.bench_feature_norm as b2

@@ -3,80 +3,66 @@ masked-secure-agg kernel roofline (bytes moved vs in-kernel PRF VPU work)."""
 import json
 import sys
 
+from repro.launch.analysis import TARGET_KIND, peaks
+
 # --- masked-kernel roofline --------------------------------------------------
-# TPU-class budget used to place the in-kernel PRF mask lane on the roofline
-# (v4-ish: HBM stream bandwidth and sustained VPU int32 throughput).
-HBM_BYTES_PER_S = 1.2e12
-VPU_INT_OPS_PER_S = 3.0e12
 THREEFRY_OPS_PER_WORD = 38  # Threefry-2x32-13: ~76 int ops / 2 output words
 
 
-def masked_kernel_roofline_row(B: int, D: int, degree: int = 0) -> dict:
+def masked_kernel_roofline_row(B: int, D: int, degree: int = 0,
+                               device_kind: str = TARGET_KIND) -> dict:
     """Roofline entry for one fused masked accumulation (B, D) session.
 
     The fused kernel reads x and uniforms (f32) and writes the int32 sum —
     the mask lane adds ZERO HBM bytes because every tile's mask words are
     regenerated in VMEM from (session key, pair, position) counters.  The
     pre-fusion path materialized a (B, D) int32 mask array in HBM (one
-    write + one read).  The lane "fits under" the memory-bound pipeline
-    when its VPU time is below the kernel's unavoidable HBM time.
+    write + one read).  ``t_mem_us`` is the kernel's HBM time at the
+    published peak of ``device_kind``; the mask lane's VPU time has no
+    published peak to divide by and is left to a chip trace.
     """
+    hbm = peaks(device_kind)["hbm_bytes_per_s"]
     deg = (B - 1) if (degree <= 0 or degree >= B - 1) else degree
     graph = "complete" if deg == B - 1 else f"ring-{deg}"
     fused_bytes = 2 * B * D * 4 + B * 4 + D * 4  # x + uniforms + w + out
     mask_hbm_bytes = 2 * B * D * 4  # materialized masks: write + readback
     mask_words = B * deg * D  # per-row neighbour streams, regenerated
-    mask_ops = mask_words * THREEFRY_OPS_PER_WORD
-    t_mem_us = fused_bytes / HBM_BYTES_PER_S * 1e6
-    t_mask_us = mask_ops / VPU_INT_OPS_PER_S * 1e6
     return {
-        "B": B, "D": D, "graph": graph,
+        "B": B, "D": D, "graph": graph, "device_kind": device_kind,
         "fused_hbm_bytes": fused_bytes,
         "mask_hbm_bytes_saved": mask_hbm_bytes,
-        "mask_vpu_ops": mask_ops,
-        "t_mem_us": t_mem_us, "t_mask_us": t_mask_us,
-        "lane_hidden": t_mask_us <= t_mem_us,
+        "mask_vpu_ops": mask_words * THREEFRY_OPS_PER_WORD,
+        "t_mem_us": fused_bytes / hbm * 1e6,
     }
 
 
-def write_masked_kernel_roofline(path: str, points) -> None:
+def write_masked_kernel_roofline(path: str, points,
+                                 device_kind: str = TARGET_KIND) -> None:
     """points: iterable of (B, D, degree) -> markdown table at ``path``."""
-    rows = [masked_kernel_roofline_row(B, D, deg) for B, D, deg in points]
+    rows = [masked_kernel_roofline_row(B, D, deg, device_kind)
+            for B, D, deg in points]
+    hbm = peaks(device_kind)["hbm_bytes_per_s"]
     with open(path, "w") as f:
         f.write(
             "# Masked secure-agg kernel roofline\n\n"
             "In-kernel PRF mask generation (Threefry-2x32-13 counters, see\n"
             "`repro/kernels/prf.py`) vs the HBM traffic of the fused\n"
-            "weight/quantize/accumulate kernel.  The mask lane moves no\n"
-            "bytes; it is hidden whenever its VPU time fits under the\n"
-            "kernel's memory time (TPU-class budget: "
-            f"{HBM_BYTES_PER_S/1e12:.1f} TB/s HBM, "
-            f"{VPU_INT_OPS_PER_S/1e12:.1f} Tops int32 VPU).\n\n"
-            "The ratio t_mask/t_mem is ~independent of D: per HBM byte the\n"
-            "lane spends ~degree * 38.5 / 8 VPU int ops, so a 13-round\n"
-            "software Threefry lane is VPU-bound at any graph degree >= 2.\n"
-            "Three ways the system keeps it off the round's critical path:\n"
-            "the sparse ring graph bounds the work per tile to O(k) streams\n"
-            "instead of O(B); `mask_mode=tee_stream` moves mask work into\n"
-            "the per-arrival encode, where it amortizes into arrival gaps\n"
-            "(see secure_agg_overhead.csv: flush-path overhead <= 1.5x);\n"
-            "and a production TPU kernel would swap the portable Threefry\n"
-            "core for the hardware PRNG (`pltpu.prng_random_bits`), which\n"
-            "the layered design isolates behind `prf.stream_at`.  What the\n"
-            "fusion buys unconditionally is the security property (masks\n"
-            "and unmasked encodings never exist in HBM) plus the\n"
-            "`mask HBM bytes saved` column of write+readback traffic.\n\n"
+            "weight/quantize/accumulate kernel, counted from shapes.  The\n"
+            "mask lane moves no bytes; what it costs is VPU work, counted\n"
+            "here as int ops.  `t_mem` is the HBM time at the published\n"
+            f"peak of a {device_kind} ({hbm / 1e9:.0f} GB/s).  No peak is\n"
+            "published for the VPU's int32 rate, so whether the lane hides\n"
+            "under the memory time is not computed here: it needs a chip\n"
+            "trace (kernel time against `t_mem`), and is not measured yet.\n\n"
             "| B | D | graph | fused HBM bytes | mask HBM bytes saved | "
-            "mask VPU ops | t_mem | t_mask | lane hidden on TPU? |\n"
-            "|---|---|---|---|---|---|---|---|---|\n")
+            "mask VPU ops | t_mem |\n"
+            "|---|---|---|---|---|---|---|\n")
         for r in rows:
             f.write(
                 f"| {r['B']} | {r['D']} | {r['graph']} | "
                 f"{r['fused_hbm_bytes']:.2e} | "
                 f"{r['mask_hbm_bytes_saved']:.2e} | "
-                f"{r['mask_vpu_ops']:.2e} | {r['t_mem_us']:.1f}us | "
-                f"{r['t_mask_us']:.1f}us | "
-                f"{'YES' if r['lane_hidden'] else 'no — VPU-bound'} |\n")
+                f"{r['mask_vpu_ops']:.2e} | {r['t_mem_us']:.1f}us |\n")
 
 
 def fmt_t(s):

@@ -50,6 +50,7 @@ from repro.core.fl import compression as comp
 from repro.core.fl import dp
 from repro.core.fl import secure_agg as sa
 from repro.kernels import prf
+from repro.kernels.ops import on_tpu
 
 
 class AggregationSpec(NamedTuple):
@@ -164,13 +165,6 @@ def encode_array(x: jnp.ndarray, scale: float, rng) -> jnp.ndarray:
     return (floor + bit).astype(jnp.int32)
 
 
-def encode_tree(tree, scale: float, rng):
-    leaves, treedef = jax.tree.flatten(tree)
-    keys = jax.random.split(rng, len(leaves))
-    return jax.tree.unflatten(
-        treedef, [encode_array(x, scale, k) for x, k in zip(leaves, keys)])
-
-
 def decode_tree(tree, scale: float):
     return jax.tree.map(lambda q: q.astype(jnp.float32) / scale, tree)
 
@@ -178,24 +172,6 @@ def decode_tree(tree, scale: float):
 # ---------------------------------------------------------------------------
 # Pairwise session masking (the in-engine secure-aggregation hot path)
 # ---------------------------------------------------------------------------
-def mask_tree(tree, slot, session: sa.MaskSession):
-    """Session masks shaped like ``tree`` for one contributor slot.
-
-    Each pytree leaf gets an independent pairwise mask stream (session key
-    folded by leaf index); summed over all of the session's slots every
-    leaf cancels to zero mod 2^32, so adding these to the encoded int32
-    tree leaves the round's modular sum bit-identical.  The session's
-    graph (degree, permutation) is shared by all pytree leaves — the graph
-    is per session, the streams per leaf.
-    """
-    leaves, treedef = jax.tree.flatten(tree)
-    return jax.tree.unflatten(treedef, [
-        sa.session_mask(x.shape, slot, session.num_slots,
-                        jax.random.fold_in(session.key, i), session.degree,
-                        session.perm)
-        for i, x in enumerate(leaves)])
-
-
 def encode_masked_contribution(x: jnp.ndarray, weight, slot,
                                spec: AggregationSpec,
                                session: sa.MaskSession, rng, *,
@@ -227,7 +203,7 @@ def encode_masked_contribution(x: jnp.ndarray, weight, slot,
         masked = _ksa.quantize_mask_prf(
             xw, spec.sa_scale, slot, jnp.stack(u_words),
             _kernel_session(session),
-            interpret=jax.default_backend() != "tpu")
+            interpret=not on_tpu())
     else:
         q = _stream_quantize(xw, spec.sa_scale, rng)
         masked = q + session.mask(xw.shape, slot)  # wraps mod 2^32
@@ -324,6 +300,40 @@ def aggregate_masked_buffer(mbuf: jnp.ndarray, present: jnp.ndarray,
                               jax.random.fold_in(rng, 0xDEE))
 
 
+def ordered_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum of a (B,) f32 vector in one fixed pairwise order.
+
+    ``jnp.sum`` leaves the association order of f32 adds to XLA, which
+    picks it from the operand's layout and fusion, so the single-host
+    engine and the tier (whose per-slot vectors arrive as (L, Bl) leaf
+    shards) could round the same values differently.  Halving a
+    zero-padded power-of-two vector fixes the order: every caller adds
+    the same tree of pairs, whatever the layout.
+    """
+    n = x.shape[0]
+    x = jnp.pad(x, (0, (1 << max(n - 1, 0).bit_length()) - n))
+    while x.shape[0] > 1:
+        h = x.shape[0] // 2
+        x = x[:h] + x[h:]
+    return x[0]
+
+
+def slot_metrics(w: jnp.ndarray, norms: jnp.ndarray,
+                 clips: jnp.ndarray) -> dict:
+    """The weighted per-slot flush metrics every engine reports.
+
+    ``w``: (B,) effective slot weights (zero for absent slots); ``norms``
+    / ``clips``: (B,) pre-clip norms and clip indicators.  One
+    implementation with :func:`ordered_sum`, so the tier's metrics equal
+    the single host's to the bit.
+    """
+    w_total = ordered_sum(w)
+    denom = jnp.maximum(w_total, 1e-9)
+    return {"update_norm": ordered_sum(norms * w) / denom,
+            "clip_fraction": ordered_sum(clips * w) / denom,
+            "weight_total": w_total}
+
+
 # ---------------------------------------------------------------------------
 # Per-contribution privatization (shared by the sync chunk scan and async)
 # ---------------------------------------------------------------------------
@@ -397,7 +407,7 @@ def encode_and_sum_rows(buf: jnp.ndarray, weights: jnp.ndarray,
         raise ValueError("pairwise masks require the secure-agg integer field "
                          "(spec.use_secure_agg)")
     B, D = buf.shape
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
     if row_sq is not None:
         sq = row_sq
     elif use_pallas:
@@ -521,13 +531,9 @@ def aggregate_buffer(buf: jnp.ndarray, weights: jnp.ndarray,
         buf, weights, uniforms, noise, spec, session=session,
         use_pallas=use_pallas)
 
-    w_total = weights.sum()
-    mean = finalize_aggregate(acc, w_total, spec, jax.random.fold_in(rng, 0xDEE))
-    stats = {
-        "update_norm": (nrm * weights).sum() / jnp.maximum(w_total, 1e-9),
-        "clip_fraction": (was_clipped * weights).sum() / jnp.maximum(w_total, 1e-9),
-        "weight_total": w_total,
-    }
+    stats = slot_metrics(weights, nrm, was_clipped)
+    mean = finalize_aggregate(acc, stats["weight_total"], spec,
+                              jax.random.fold_in(rng, 0xDEE))
     return mean, stats
 
 
@@ -745,28 +751,6 @@ def plan_sq_norms(plan: ParamPlan, chunk_arrays: Sequence[jnp.ndarray]):
     return sq
 
 
-def plan_mask_tree(tree, slot, plan: ParamPlan, sessions):
-    """Plan form of :func:`mask_tree`: per-chunk sessions over the model.
-
-    Leaf ``i`` of chunk ``c`` draws its pairwise stream from the CHUNK's
-    session key folded by the chunk-LOCAL leaf index — each chunk is an
-    independent complete session whose masks cancel on their own, exactly
-    as in the streamed engines.  The degenerate single-chunk plan (one
-    session, local == global leaf indices) reproduces :func:`mask_tree`
-    bit-for-bit.
-    """
-    leaves = plan.leaves_of(tree)
-    out = []
-    for c, ck in enumerate(plan.chunks):
-        s = sessions[c]
-        for i in range(ck.leaf_lo, ck.leaf_hi):
-            out.append(sa.session_mask(
-                leaves[i].shape, slot, s.num_slots,
-                jax.random.fold_in(s.key, i - ck.leaf_lo), s.degree,
-                s.perm))
-    return jax.tree.unflatten(plan.treedef, out)
-
-
 def plan_sessions(spec: AggregationSpec, plan: ParamPlan, key, *,
                   num_slots: Optional[int] = None, slot_offset=0):
     """One :class:`secure_agg.MaskSession` per chunk (or None if no key)."""
@@ -852,7 +836,7 @@ def encode_plan_flat(xs: Sequence[jnp.ndarray], weight, slot,
                 q_full = _ksa.rotate_quantize_prf(
                     xw, spec.sa_scale, op.key_words, jnp.stack(u_words),
                     u_offset=ck.offset,
-                    interpret=jax.default_backend() != "tpu")
+                    interpret=not on_tpu())
             else:
                 if op.mode == "sketch":
                     y = xw if op.full == ck.size else jnp.pad(
@@ -872,12 +856,12 @@ def encode_plan_flat(xs: Sequence[jnp.ndarray], weight, slot,
                 row = jnp.pad(row, (0, wc.padded - wc.size))
             rows.append(row)
             continue
-        if masked and use_pallas:
+        if use_pallas:
             from repro.kernels import secure_agg as _ksa
             row = _ksa.quantize_mask_prf(
                 xw, spec.sa_scale, slot, jnp.stack(u_words),
-                _kernel_session(sessions[c]), u_offset=ck.offset,
-                interpret=jax.default_backend() != "tpu")
+                _kernel_session(sessions[c]) if masked else None,
+                u_offset=ck.offset, interpret=not on_tpu())
         else:
             xf = xw * spec.sa_scale
             floor = jnp.floor(xf)
@@ -1010,15 +994,9 @@ def aggregate_plan_buffer(bufs: Sequence[jnp.ndarray], weights: jnp.ndarray,
     accs, nrm, was_clipped = encode_plan_rows(
         bufs, weights, uniforms, noise, spec, plan, sessions=sessions,
         use_pallas=use_pallas)
-    w_total = weights.sum()
-    mean = finalize_plan_aggregate(accs, w_total, spec, plan,
+    stats = slot_metrics(weights, nrm, was_clipped)
+    mean = finalize_plan_aggregate(accs, stats["weight_total"], spec, plan,
                                    jax.random.fold_in(rng, 0xDEE))
-    stats = {
-        "update_norm": (nrm * weights).sum() / jnp.maximum(w_total, 1e-9),
-        "clip_fraction": (was_clipped * weights).sum()
-        / jnp.maximum(w_total, 1e-9),
-        "weight_total": w_total,
-    }
     return mean, stats
 
 

@@ -38,6 +38,7 @@ from repro.core.fl import aggregation as agg
 from repro.core.fl import compression as comp
 from repro.core.fl import secure_agg as sa
 from repro.core.fl.server_opt import build_server_opt
+from repro.kernels.ops import on_tpu
 
 # the PR 8 degradation-counter vocabulary, now telemetry-backed (the
 # ``fault_metrics`` attribute is a deprecated dict view over these)
@@ -115,7 +116,7 @@ def build_async_buffer_step(params, fl_cfg, *, buffer_size: int,
     mean delta (up to fixed-point stochastic rounding).
     """
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = on_tpu()
     if mask_mode not in ("off", "tee"):
         raise ValueError(f"mask_mode {mask_mode!r}: expected 'off' or 'tee'")
     spec = agg.make_spec(fl_cfg, buffer_size)
@@ -141,13 +142,8 @@ def build_async_buffer_step(params, fl_cfg, *, buffer_size: int,
             bufs, w, spec, plan, rng, sessions=sessions,
             use_pallas=use_pallas)
         new_params, new_opt = server.apply(params, opt_state, mean_delta)
-        metrics = {
-            "update_norm": stats["update_norm"],
-            "clip_fraction": stats["clip_fraction"],
-            "weight_total": stats["weight_total"],
-            "staleness_mean": (staleness * valid).sum()
-            / jnp.maximum(valid.sum(), 1.0),
-        }
+        metrics = dict(stats, staleness_mean=agg.ordered_sum(
+            staleness * valid) / jnp.maximum(agg.ordered_sum(valid), 1.0))
         return new_params, new_opt, metrics
 
     return jax.jit(step)
@@ -195,7 +191,8 @@ def build_masked_async_buffer_step(params, fl_cfg, *, buffer_size: int,
              clips, session_key, rng):
         mbufs = mbuf if isinstance(mbuf, (tuple, list)) else (mbuf,)
         w = weights * present
-        w_total = w.sum()
+        metrics = agg.slot_metrics(w, norms, clips)
+        w_total = metrics["weight_total"]
         sessions = agg.plan_sessions(spec, plan, session_key) if masked \
             else None
         # compressed-wire decode: re-derive the session's operators from
@@ -205,14 +202,8 @@ def build_masked_async_buffer_step(params, fl_cfg, *, buffer_size: int,
             mbufs, present, w_total, spec, plan, sessions, rng,
             recover=recover, masked=masked, ops=ops)
         new_params, new_opt = server.apply(params, opt_state, mean_delta)
-        denom = jnp.maximum(w_total, 1e-9)
-        metrics = {
-            "update_norm": (norms * w).sum() / denom,
-            "clip_fraction": (clips * w).sum() / denom,
-            "weight_total": w_total,
-            "staleness_mean": (staleness * present).sum()
-            / jnp.maximum(present.sum(), 1.0),
-        }
+        metrics["staleness_mean"] = agg.ordered_sum(staleness * present) \
+            / jnp.maximum(agg.ordered_sum(present), 1.0)
         return new_params, new_opt, metrics
 
     return jax.jit(step)
@@ -351,7 +342,7 @@ class AsyncServer:
         self._session_base = jax.random.PRNGKey(session_seed)
         self._push_base = jax.random.PRNGKey(0xA5)
         if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
+            use_pallas = on_tpu()
 
         self._plan = agg.plan_for(params, fl_cfg)
         self._opt_state = build_server_opt(fl_cfg).init(params)

@@ -179,6 +179,36 @@ def wire_chunks(cspec: CompressionSpec, chunks: Sequence) -> Tuple[
     return tuple(out)
 
 
+def smallest_m(ranks: jnp.ndarray, m: int) -> jnp.ndarray:
+    """Ascending positions of the ``m`` smallest int32 ``ranks``, ties
+    taken in position order — ``sort(argsort(ranks)[:m])`` without sorting.
+
+    A bisection over the 32-bit order finds the m-th smallest rank ``t``
+    (32 counting passes); every rank below ``t`` is kept, plus the first
+    ties at ``t`` up to ``m``.  Sorting a model-width chunk costs the TPU
+    compiler over a minute; this costs it a few passes.
+    """
+    # flip the sign bit: signed int32 order becomes unsigned order.  The
+    # barrier materializes the ranks once: fused into the 32 counting
+    # passes and the cumsum, the PRF that makes them takes the TPU
+    # compiler minutes.
+    u = jax.lax.optimization_barrier(jax.lax.bitcast_convert_type(
+        ranks.astype(jnp.int32), jnp.uint32) ^ jnp.uint32(1 << 31))
+
+    def bisect(_, lohi):
+        lo, hi = lohi
+        mid = lo + (hi - lo) // 2
+        enough = jnp.sum((u <= mid).astype(jnp.int32)) >= m
+        return jnp.where(enough, lo, mid + 1), jnp.where(enough, mid, hi)
+
+    t, _ = jax.lax.fori_loop(0, 32, bisect,
+                             (jnp.uint32(0), jnp.uint32(0xFFFFFFFF)))
+    below, ties = u < t, u == t
+    room = m - jnp.sum(below.astype(jnp.int32))
+    keep = below | (ties & (jnp.cumsum(ties.astype(jnp.int32)) <= room))
+    return jnp.nonzero(keep, size=m)[0].astype(jnp.int32)
+
+
 def chunk_operators(op_key, mode: str, size: int, rate: float) -> ChunkOps:
     """Realize one chunk's operator from its fold-in key.
 
@@ -192,7 +222,7 @@ def chunk_operators(op_key, mode: str, size: int, rate: float) -> ChunkOps:
     m = max(1, math.ceil(rate * size))
     ow0, ow1 = prf.key_words(op_key)
     ranks = prf.stream_block(ow0, ow1, full, tag=prf.TAG_SELECT)
-    idx = jnp.sort(jnp.argsort(ranks)[:m]).astype(jnp.int32)
+    idx = smallest_m(ranks, m)
     signs = None
     if mode == "sketch":
         bits = prf.stream_block(ow0, ow1, full, tag=prf.TAG_SIGN)
@@ -204,21 +234,26 @@ def chunk_operators(op_key, mode: str, size: int, rate: float) -> ChunkOps:
 def fwht(x: jnp.ndarray) -> jnp.ndarray:
     """Orthonormal fast Walsh–Hadamard transform over the last axis.
 
-    The classic in-place butterfly as a reshape cascade — at stage ``h``
-    the last axis is viewed as ``(n/(2h), 2, h)`` and the two halves
-    combine to ``(a+b, a-b)``.  One final ``1/sqrt(n)`` makes it
-    orthonormal (and therefore self-inverse).  The kernel body and the
-    ref.py oracle replicate this EXACT operation order, so host, kernel
-    and oracle agree bit-for-bit.
+    The classic in-place butterfly: at stage ``h`` element ``i`` meets its
+    partner ``i ^ h`` and keeps ``x_i + x_p`` when bit ``h`` of ``i`` is
+    clear, ``x_p - x_i`` when it is set — the ``(a+b, a-b)`` pair of the
+    butterfly.  The partner comes from a rotation of the axis by ``h``
+    either way, so no stage ever views the axis with a short minor
+    dimension (which a TPU would pad out to full lanes).  One final
+    ``1/sqrt(n)`` makes it orthonormal (and therefore self-inverse).  The
+    kernel body and the ref.py oracle do the same adds in the same order,
+    so host, kernel and oracle agree bit-for-bit.
     """
-    lead, n = x.shape[:-1], x.shape[-1]
+    n = x.shape[-1]
     if n & (n - 1):
         raise ValueError(f"fwht length {n} must be a power of two")
+    lane = jnp.arange(n, dtype=jnp.int32)
     h = 1
     while h < n:
-        x = x.reshape(lead + (n // (2 * h), 2, h))
-        a, b = x[..., 0, :], x[..., 1, :]
-        x = jnp.stack((a + b, a - b), axis=-2).reshape(lead + (n,))
+        lo = (lane & h) == 0
+        partner = jnp.where(lo, jnp.roll(x, -h, axis=-1),
+                            jnp.roll(x, h, axis=-1))
+        x = jnp.where(lo, x + partner, partner - x)
         h *= 2
     return x * jnp.float32(1.0 / math.sqrt(n))
 

@@ -68,12 +68,8 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # moved out of experimental on newer jax
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    shard_map = jax.shard_map
 
 from repro.core import telemetry as tele
 from repro.core.fl import aggregation as agg
@@ -81,6 +77,7 @@ from repro.core.fl import secure_agg as sa
 from repro.core.fl.async_fl import (FAULT_METRIC_KEYS, ClientPush,
                                     batch_count, staleness_weight)
 from repro.core.fl.server_opt import build_server_opt
+from repro.kernels.ops import on_tpu
 from repro.launch.mesh import (LEAF_AXIS, leaves_per_device, make_agg_mesh,
                                make_leaf_mesh)
 from repro.launch.sharding import hierarchy_shardings
@@ -143,6 +140,16 @@ def _pad_to(x: jnp.ndarray, width: int) -> jnp.ndarray:
     return jnp.pad(x, (0, width - x.shape[-1]))
 
 
+def _take_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``x[idx]`` for a short traced row index, one dynamic slice per row.
+
+    The same rows as ``jnp.take(x, idx, axis=0)``; as one XLA gather of
+    model-width rows it costs the TPU compiler close to a minute.
+    """
+    return jnp.stack([jax.lax.dynamic_index_in_dim(x, idx[j], keepdims=False)
+                      for j in range(idx.shape[0])])
+
+
 def _as_chunks(buf) -> tuple:
     """Normalize a buffer argument to the plan's per-chunk tuple — a bare
     array is the degenerate single-chunk layout."""
@@ -170,19 +177,13 @@ def _finalize_root(params, opt_state, accs, w, norms, clips, staleness,
     gate); ``participation``: (B,) 1/0 present (streamed engines) or valid
     (batched engines) vector — the staleness_mean denominator.
     """
-    w_total = w.sum()
-    mean = agg.finalize_plan_aggregate(accs, w_total, spec, plan,
-                                       jax.random.fold_in(rng, 0xDEE),
+    metrics = agg.slot_metrics(w, norms, clips)
+    mean = agg.finalize_plan_aggregate(accs, metrics["weight_total"], spec,
+                                       plan, jax.random.fold_in(rng, 0xDEE),
                                        ops=ops)
     new_params, new_opt = server.apply(params, opt_state, mean)
-    denom = jnp.maximum(w_total, 1e-9)
-    metrics = {
-        "update_norm": (norms * w).sum() / denom,
-        "clip_fraction": (clips * w).sum() / denom,
-        "weight_total": w_total,
-        "staleness_mean": (staleness * participation).sum()
-        / jnp.maximum(participation.sum(), 1.0),
-    }
+    metrics["staleness_mean"] = agg.ordered_sum(staleness * participation) \
+        / jnp.maximum(agg.ordered_sum(participation), 1.0)
     return new_params, new_opt, metrics
 
 
@@ -262,7 +263,7 @@ def build_sharded_masked_step(params, fl_cfg, *, num_leaves: int,
                 leaf_fn, mesh=mesh,
                 in_specs=(P(LEAF_AXIS), P(LEAF_AXIS), P(), P(LEAF_AXIS),
                           P(LEAF_AXIS), P(LEAF_AXIS), P()),
-                out_specs=P(), check_rep=False,
+                out_specs=P(), check_vma=False,
             )(rows, pres_full, pres_full, los, his, ews, session_key)
         elif recover:  # streamed-unmasked partial flush: gate, no shares
 
@@ -274,7 +275,7 @@ def build_sharded_masked_step(params, fl_cfg, *, num_leaves: int,
 
             accs = shard_map(
                 leaf_fn, mesh=mesh, in_specs=(P(LEAF_AXIS), P(LEAF_AXIS)),
-                out_specs=P(), check_rep=False)(rows, pres_full)
+                out_specs=P(), check_vma=False)(rows, pres_full)
         else:  # complete session: masks provably cancel in the plain sum
 
             def leaf_fn(rows_l):
@@ -282,7 +283,7 @@ def build_sharded_masked_step(params, fl_cfg, *, num_leaves: int,
                     tuple(jnp.sum(r, axis=0) for r in rows_l), LEAF_AXIS)
 
             accs = shard_map(leaf_fn, mesh=mesh, in_specs=(P(LEAF_AXIS),),
-                             out_specs=P(), check_rep=False)(rows)
+                             out_specs=P(), check_vma=False)(rows)
 
         w = weights.reshape(B) * pres_full
         return _finalize_root(params, opt_state, accs, w, norms.reshape(B),
@@ -316,7 +317,7 @@ def build_sharded_buffer_step(params, fl_cfg, *, num_leaves: int,
     single-host step at ``buffer_size = num_leaves * leaf_buffer``.
     """
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = on_tpu()
     if mask_mode not in ("off", "tee"):
         raise ValueError(f"mask_mode {mask_mode!r}: expected 'off' or 'tee'")
     B = num_leaves * leaf_buffer
@@ -375,7 +376,7 @@ def build_sharded_buffer_step(params, fl_cfg, *, num_leaves: int,
             in_specs.append(P())
         accs, nrm, was_clipped = shard_map(
             leaf_fn, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=(P(), P(LEAF_AXIS), P(LEAF_AXIS)), check_rep=False,
+            out_specs=(P(), P(LEAF_AXIS), P(LEAF_AXIS)), check_vma=False,
         )(*args)
 
         return _finalize_root(params, opt_state, accs, w_full, nrm,
@@ -476,7 +477,7 @@ def build_two_level_masked_step(params, fl_cfg, *, num_leaves: int,
         accs = shard_map(
             dev_fn, mesh=mesh,
             in_specs=(P(LEAF_AXIS), P(LEAF_AXIS), P()),
-            out_specs=P(), check_rep=False,
+            out_specs=P(), check_vma=False,
         )(bufs, present, session_key)
 
         pres_full = present.reshape(B)
@@ -519,7 +520,7 @@ def build_two_level_buffer_step(params, fl_cfg, *, num_leaves: int,
     session's masks cancel exactly as the global session's did).
     """
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = on_tpu()
     B = num_leaves * leaf_buffer
     spec = agg.make_spec(fl_cfg, B)
     if not spec.use_secure_agg:
@@ -586,7 +587,7 @@ def build_two_level_buffer_step(params, fl_cfg, *, num_leaves: int,
         in_specs.append(P())
         accs, nrm, was_clipped = shard_map(
             dev_fn, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=(P(), P(LEAF_AXIS), P(LEAF_AXIS)), check_rep=False,
+            out_specs=(P(), P(LEAF_AXIS), P(LEAF_AXIS)), check_vma=False,
         )(*args)
         nrm, was_clipped = nrm.reshape(B), was_clipped.reshape(B)
 
@@ -689,7 +690,7 @@ class ShardedAsyncServer:
         self._session_base = jax.random.PRNGKey(session_seed)
         self._push_base = jax.random.PRNGKey(0xA5)
         if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
+            use_pallas = on_tpu()
         if mesh is None:
             mesh = (make_leaf_mesh(num_leaves) if two_level
                     else make_agg_mesh(num_leaves))
@@ -826,37 +827,50 @@ class ShardedAsyncServer:
                 """
                 chunks_raw = plan.chunk_arrays(deltas, leading=1, pad=True)
                 kb = idx.shape[1]
-                routed = tuple(
-                    jnp.take(cr, idx.reshape(-1), axis=0).reshape(L, kb, -1)
-                    for cr in chunks_raw)
+                routed = tuple(_take_rows(cr, idx.reshape(-1))
+                               .reshape(L, kb, -1) for cr in chunks_raw)
 
                 def dev_fn(buf_b, wts_b, norms_b, clips_b, stal_b, routed_b,
                            lslot_b, valid_b, stals_b, skey, pkey):
                     dev = jax.lax.axis_index(LEAF_AXIS)
                     gleaves = dev * lpd + jnp.arange(lpd, dtype=jnp.int32)
+                    # ONE vmap over this device's (leaf, row) pairs: the
+                    # rows' encode is one kernel launch whose grid walks
+                    # the pairs
+                    kb = lslot_b.shape[1]
+                    gslots = (gleaves[:, None] * Bl + lslot_b).reshape(-1)
 
-                    def one_leaf(g, buf_l, wts_l, norms_l, clips_l, stal_l,
-                                 raw_l, sl, vld, st):
-                        rows_e, w, nrm, cl = jax.vmap(
-                            lambda r, s, t: encode_row(r, g * Bl + s, t,
-                                                       skey, pkey))(
-                            raw_l, sl, st)
+                    def flat(a):
+                        return a.reshape((lpd * kb,) + a.shape[2:])
+
+                    def per_leaf(a):
+                        return a.reshape((lpd, kb) + a.shape[1:])
+
+                    rows_e, w, nrm, cl = jax.vmap(
+                        lambda r, s, t: encode_row(r, s, t, skey, pkey))(
+                        tuple(flat(r) for r in routed_b), gslots,
+                        flat(stals_b))
+
+                    def one_leaf(buf_l, wts_l, norms_l, clips_l, stal_l,
+                                 rows_l, w_l, nrm_l, cl_l, sl, vld, st):
                         tgt = jnp.where(vld > 0, sl, Bl)  # Bl -> dropped
                         return (tuple(b.at[tgt].set(r, mode="drop")
-                                      for b, r in zip(buf_l, rows_e)),
-                                wts_l.at[tgt].set(w, mode="drop"),
-                                norms_l.at[tgt].set(nrm, mode="drop"),
-                                clips_l.at[tgt].set(cl, mode="drop"),
+                                      for b, r in zip(buf_l, rows_l)),
+                                wts_l.at[tgt].set(w_l, mode="drop"),
+                                norms_l.at[tgt].set(nrm_l, mode="drop"),
+                                clips_l.at[tgt].set(cl_l, mode="drop"),
                                 stal_l.at[tgt].set(st, mode="drop"))
 
                     return jax.vmap(one_leaf)(
-                        gleaves, buf_b, wts_b, norms_b, clips_b, stal_b,
-                        routed_b, lslot_b, valid_b, stals_b)
+                        buf_b, wts_b, norms_b, clips_b, stal_b,
+                        tuple(per_leaf(r) for r in rows_e), per_leaf(w),
+                        per_leaf(nrm), per_leaf(cl), lslot_b, valid_b,
+                        stals_b)
 
                 return shard_map(
                     dev_fn, mesh=self.mesh,
                     in_specs=(P(LEAF_AXIS),) * 9 + (P(), P()),
-                    out_specs=(P(LEAF_AXIS),) * 5, check_rep=False,
+                    out_specs=(P(LEAF_AXIS),) * 5, check_vma=False,
                 )(buf, wts, norms, clips, stal, routed, lslot, valid, stals,
                   session_key, push_key)
 
@@ -900,9 +914,8 @@ class ShardedAsyncServer:
                 Bl, out of range -> scatter-drop)."""
                 kb = idx.shape[1]
                 flat = idx.reshape(-1)
-                routed = tuple(
-                    jnp.take(wr, flat, axis=0).reshape(L, kb, -1)
-                    for wr in wrows)
+                routed = tuple(_take_rows(wr, flat).reshape(L, kb, -1)
+                               for wr in wrows)
                 wv = jnp.take(w, flat).reshape(L, kb)
                 nv = jnp.take(nrm, flat).reshape(L, kb)
                 cv = jnp.take(clipped, flat).reshape(L, kb)
@@ -931,7 +944,7 @@ class ShardedAsyncServer:
                 return shard_map(
                     dev_fn, mesh=self.mesh,
                     in_specs=(P(LEAF_AXIS),) * 12,
-                    out_specs=(P(LEAF_AXIS),) * 5, check_rep=False,
+                    out_specs=(P(LEAF_AXIS),) * 5, check_vma=False,
                 )(bufs, wts, norms, clips, stal, routed, lslot, valid,
                   stals, wv, nv, cv)
 

@@ -5,14 +5,15 @@ One round =
      (the paper's regime: ~one sample per device, so per-client == per-example);
   2. each client's model delta is L2-clipped (DP-SGD) and, in ``device`` noise
      placement, locally noised;
-  3. deltas are fixed-point quantized and summed with wraparound int32
-     arithmetic — bit-identical to the pairwise-masked secure-aggregation sum
-     (masks cancel; see core/fl/secure_agg.py), lowering to one big integer
-     all-reduce over the (pod, data) axes.  With
-     ``fl_cfg.secure_agg_masked`` the masks are real, not notional: every
-     cohort slot adds its pairwise session mask — one batched counter-PRF
-     sweep per slot (``secure_agg.session_mask``; graph degree from
-     ``fl_cfg.secure_agg_degree``) — to the encoded delta inside the scan,
+  3. deltas are fixed-point quantized by the streamed engines' client
+     encode (``aggregation.encode_plan_contribution``: PRF stochastic
+     rounding, the fused Pallas kernel on a TPU) and summed with
+     wraparound int32 arithmetic — bit-identical to the pairwise-masked
+     secure-aggregation sum (masks cancel; see core/fl/secure_agg.py),
+     lowering to one big integer all-reduce over the (pod, data) axes.
+     With ``fl_cfg.secure_agg_masked`` the masks are real, not notional:
+     every cohort slot adds its pairwise session mask (graph degree from
+     ``fl_cfg.secure_agg_degree``) to its encoded rows inside the scan,
      and the round stays bit-identical because they cancel;
   4. in ``tee`` placement, Gaussian noise is added once to the decoded
      aggregate inside the trusted boundary;
@@ -37,6 +38,7 @@ import jax.numpy as jnp
 from repro.core import telemetry as tele
 from repro.core.fl import aggregation as agg
 from repro.core.fl.server_opt import build_server_opt
+from repro.kernels.ops import on_tpu
 
 
 class FLState(NamedTuple):
@@ -129,13 +131,49 @@ def build_scaffold_client_update(loss_fn: Callable, fl_cfg) -> Callable:
 
 
 # ---------------------------------------------------------------------------
-# Fixed-point secure-aggregation encoding now lives in the shared engine
-# (core/fl/aggregation.py); these aliases keep the historical names working.
+# Fixed-point secure-aggregation encoding lives in the shared engine
+# (core/fl/aggregation.py); the historical name of the scale stays.
 # ---------------------------------------------------------------------------
 _sa_scale = agg.fixed_point_scale
-_sa_encode = agg.encode_array
-_sa_encode_tree = agg.encode_tree
-_sa_decode_tree = agg.decode_tree
+
+
+def _contribution_fn(client_update: Callable, spec, plan, sessions, *,
+                     masked: bool):
+    """One client's round contribution, shared by both round builders.
+
+    ``contribute(params, cbatch, rng, weight, slot) -> (contribution,
+    loss, pre-clip norm, clipped)``.  Under secure aggregation the
+    contribution is the streamed engines' client encode
+    (``aggregation.encode_plan_contribution``): clip, weight, device
+    noise, PRF stochastic rounding and, when ``masked``, the slot's
+    pairwise mask, as per-chunk int32 rows of the ``ParamPlan``.  Masked
+    and unmasked rounds consume the same uniforms, so their modular sums
+    are bit-identical.  On a TPU the encode runs the fused Pallas kernel;
+    elsewhere its jnp twin, bit-identical to it.  Without secure
+    aggregation the contribution is the clipped, weighted f32 delta.
+    """
+    use_pallas = on_tpu()
+
+    def contribute(params, cbatch, rng, w, slot):
+        delta, loss = client_update(params, cbatch, rng)
+        if spec.use_secure_agg:
+            rows, nrm, clipped = agg.encode_plan_contribution(
+                delta, w, slot, spec, plan, sessions, rng, masked=masked,
+                use_pallas=use_pallas)
+            return rows, loss, nrm, clipped
+        delta, nrm, clipped = agg.privatize_contribution(delta, spec, rng)
+        return (jax.tree.map(lambda d: d * w, delta), loss, nrm,
+                clipped.astype(jnp.float32))
+
+    return contribute
+
+
+def _finalize(acc, w_s, spec, plan, rng):
+    """Decode + weight-normalize + TEE noise: the shared engine semantics."""
+    rng = jax.random.fold_in(rng, 0xDEE)
+    if spec.use_secure_agg:
+        return agg.finalize_plan_aggregate(acc, w_s, spec, plan, rng)
+    return agg.finalize_aggregate(acc, w_s, spec, rng)
 
 
 # ---------------------------------------------------------------------------
@@ -149,6 +187,10 @@ def build_round_step(loss_fn: Callable, fl_cfg, *, cohort_size: int,
            (per-client on-device data), plus optional 'weight' (cohort,)
            from the Orchestrator's sample-submission control.
 
+    Each chunk of ``clients_per_chunk`` clients is vmapped through
+    :func:`_contribution_fn` (the streamed engines' client encode) and
+    modular-summed into per-ParamPlan-chunk int32 accumulators.
+
     The returned step is instrumented with ``round.execute`` spans on the
     ``telemetry`` registry (the process default when None).  The span label
     is a host-side call counter, never a traced value — callers are free to
@@ -160,21 +202,14 @@ def build_round_step(loss_fn: Callable, fl_cfg, *, cohort_size: int,
         client_update = build_client_update(loss_fn, fl_cfg)
         server = build_server_opt(fl_cfg)
         spec = agg.make_spec(fl_cfg, cohort_size)
-        use_secure_agg = spec.use_secure_agg
-        sa_scale = spec.sa_scale
-        masked = use_secure_agg and getattr(fl_cfg, "secure_agg_masked",
-                                            False)
+        masked = spec.use_secure_agg and getattr(fl_cfg, "secure_agg_masked",
+                                                 False)
 
         if clients_per_chunk <= 0:
             clients_per_chunk = cohort_size if client_parallel else 1
         m = clients_per_chunk
         assert cohort_size % m == 0
         n_chunks = cohort_size // m
-
-    def one_client(params, cbatch, rng):
-        delta, loss = client_update(params, cbatch, rng)
-        delta, nrm, was_clipped = agg.privatize_contribution(delta, spec, rng)
-        return delta, loss, nrm, was_clipped
 
     def round_step(state: FLState, batch, rng):
         params = state.params
@@ -201,63 +236,34 @@ def build_round_step(loss_fn: Callable, fl_cfg, *, cohort_size: int,
         plan = agg.plan_for(params, fl_cfg)
         sessions = agg.plan_sessions(
             spec, plan, jax.random.fold_in(rng, 0x5E55)) if masked else None
+        contribute = jax.vmap(
+            _contribution_fn(client_update, spec, plan, sessions,
+                             masked=masked),
+            in_axes=(None, 0, 0, 0, 0))
 
         deferred = getattr(fl_cfg, "deferred_agg", False) and m > 1
-        if deferred:
-            # per-client-slot partial accumulators: slot axis shards like the
-            # client axis, so the chunk-scan accumulation is collective-free
-            # and the cross-device reduction happens ONCE after the scan.
-            acc0 = agg.zero_accumulator(params, spec, leading=(m,))
+        # per-client-slot partial accumulators under deferred_agg: the slot
+        # axis shards like the client axis, so the chunk-scan accumulation
+        # is collective-free and the cross-device reduction happens ONCE
+        # after the scan.
+        lead = (m,) if deferred else ()
+        if spec.use_secure_agg:  # per-chunk int32 rows of the ParamPlan
+            acc0 = tuple(jnp.zeros(lead + (ck.padded,), jnp.int32)
+                         for ck in plan.chunks)
         else:
-            acc0 = agg.zero_accumulator(params, spec)
+            acc0 = agg.zero_accumulator(params, spec, leading=lead)
         stats0 = (jnp.zeros(()), jnp.zeros(()), jnp.zeros(()), jnp.zeros(()))
 
         def chunk_body(carry, xs):
             acc, (loss_s, norm_s, clip_s, w_s) = carry
             cbatch, crng, w, cslot = xs
-
-            if m == 1:
-                squeezed = jax.tree.map(lambda x: x[0], cbatch)
-                delta, loss, nrm, was_clipped = one_client(params, squeezed, crng[0])
-                w0 = w[0]
-                delta = jax.tree.map(lambda d: d * w0, delta)
-                if use_secure_agg:
-                    enc = _sa_encode_tree(delta, sa_scale,
-                                          jax.random.fold_in(crng[0], 2))
-                    if masked:
-                        enc = jax.tree.map(
-                            lambda e, mk: e + mk, enc,
-                            agg.plan_mask_tree(params, cslot[0], plan,
-                                               sessions))
-                else:
-                    enc = delta
-                acc = jax.tree.map(lambda a, e: a + e, acc, enc)
-                stats = (loss_s + loss * w0, norm_s + nrm * w0,
-                         clip_s + was_clipped.astype(jnp.float32) * w0, w_s + w0)
-            else:
-                deltas, losses, nrms, clips = jax.vmap(
-                    one_client, in_axes=(None, 0, 0))(params, cbatch, crng)
-                deltas = jax.tree.map(
-                    lambda d: d * w.reshape((m,) + (1,) * (d.ndim - 1)), deltas)
-                if use_secure_agg:
-                    encs = jax.vmap(_sa_encode_tree, in_axes=(0, None, 0))(
-                        deltas, sa_scale, crng)
-                    if masked:
-                        mks = jax.vmap(
-                            lambda s: agg.plan_mask_tree(params, s, plan,
-                                                         sessions))(cslot)
-                        encs = jax.tree.map(lambda e, mk: e + mk, encs, mks)
-                else:
-                    encs = deltas
-                if deferred:
-                    acc = jax.tree.map(lambda a, e: a + e.astype(a.dtype),
-                                       acc, encs)
-                else:
-                    acc = jax.tree.map(lambda a, e: a + e.sum(0).astype(a.dtype),
-                                       acc, encs)
-                stats = (loss_s + (losses * w).sum(), norm_s + (nrms * w).sum(),
-                         clip_s + (clips.astype(jnp.float32) * w).sum(),
-                         w_s + w.sum())
+            encs, losses, nrms, clips = contribute(params, cbatch, crng, w,
+                                                   cslot)
+            # int32 rows wrap mod 2^32: the masked sum equals the unmasked
+            acc = jax.tree.map(lambda a, e: a + (e if deferred else e.sum(0)),
+                               acc, encs)
+            stats = (loss_s + (losses * w).sum(), norm_s + (nrms * w).sum(),
+                     clip_s + (clips * w).sum(), w_s + w.sum())
             return (acc, stats), None
 
         (acc, (loss_s, norm_s, clip_s, w_s)), _ = jax.lax.scan(
@@ -266,9 +272,7 @@ def build_round_step(loss_fn: Callable, fl_cfg, *, cohort_size: int,
         w_total = jnp.maximum(w_s, 1e-9)
         if deferred:
             acc = jax.tree.map(lambda a: a.sum(0), acc)  # one reduction/round
-        # decode + weight-normalize + TEE noise draw: shared engine semantics
-        mean_delta = agg.finalize_aggregate(acc, w_s, spec,
-                                            jax.random.fold_in(rng, 0xDEE))
+        mean_delta = _finalize(acc, w_s, spec, plan, rng)
 
         new_params, new_opt = server.apply(params, state.opt_state, mean_delta)
         metrics = {
@@ -327,10 +331,6 @@ def build_sharded_round_step(loss_fn: Callable, fl_cfg, *, cohort_size: int,
     """
     from jax.sharding import PartitionSpec as P
 
-    try:  # moved out of experimental on newer jax
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # pragma: no cover
-        shard_map = jax.shard_map
     from repro.launch.mesh import LEAF_AXIS, make_agg_mesh
 
     tel = telemetry if telemetry is not None else tele.get_default()
@@ -347,7 +347,6 @@ def build_sharded_round_step(loss_fn: Callable, fl_cfg, *, cohort_size: int,
         masked = getattr(fl_cfg, "secure_agg_masked", False)
         if mesh is None:
             mesh = make_agg_mesh(num_leaves)
-        sa_scale = spec.sa_scale
 
     def round_step(state: FLState, batch, rng):
         params = state.params
@@ -357,43 +356,27 @@ def build_sharded_round_step(loss_fn: Callable, fl_cfg, *, cohort_size: int,
         batch = {k: v for k, v in batch.items() if k != "weight"}
         rngs = jax.random.split(rng, cohort_size)  # client c -> rngs[c]
         skey = jax.random.fold_in(rng, 0x5E55) if masked else None
+        plan = agg.plan_for(params, fl_cfg)
 
         def leaf_fn(params, cbatch_l, rngs_l, w_l, *mask_args):
-            slot0 = jax.lax.axis_index(LEAF_AXIS) * m
-
-            def one_client(cb, crng):
-                delta, loss = client_update(params, cb, crng)
-                delta, nrm, clipped = agg.privatize_contribution(
-                    delta, spec, crng)
-                return delta, loss, nrm, clipped
-
-            deltas, losses, nrms, clips = jax.vmap(one_client)(cbatch_l,
-                                                               rngs_l)
-            deltas = jax.tree.map(
-                lambda d: d * w_l.reshape((m,) + (1,) * (d.ndim - 1)),
-                deltas)
-            encs = jax.vmap(agg.encode_tree, in_axes=(0, None, 0))(
-                deltas, sa_scale, rngs_l)
-            if masked:
-                # every leaf derives the SAME per-chunk sessions (incl. the
-                # random k-regular graphs) from the replicated session key —
-                # no permutation array needs threading through shard_map
-                (skey_l,) = mask_args
-                plan = agg.plan_for(params, fl_cfg)
-                sessions = agg.plan_sessions(spec, plan, skey_l)
-                slots = slot0 + jnp.arange(m, dtype=jnp.int32)
-                mks = jax.vmap(
-                    lambda s: agg.plan_mask_tree(params, s, plan,
-                                                 sessions))(slots)
-                encs = jax.tree.map(lambda e, mk: e + mk, encs, mks)
-            # the root combine: ONE integer all-reduce per round
-            acc = jax.tree.map(
-                lambda e: jax.lax.psum(e.sum(0), LEAF_AXIS), encs)
+            # every leaf derives the SAME per-chunk sessions (incl. the
+            # random k-regular graphs) from the replicated session key —
+            # no permutation array needs threading through shard_map
+            sessions = agg.plan_sessions(spec, plan, mask_args[0]) \
+                if masked else None
+            slots = (jax.lax.axis_index(LEAF_AXIS) * m
+                     + jnp.arange(m, dtype=jnp.int32))
+            contribute = _contribution_fn(client_update, spec, plan,
+                                          sessions, masked=masked)
+            rows, losses, nrms, clips = jax.vmap(
+                contribute, in_axes=(None, 0, 0, 0, 0))(
+                    params, cbatch_l, rngs_l, w_l, slots)
+            # the root combine: ONE integer all-reduce per chunk and round
+            acc = tuple(jax.lax.psum(r.sum(0), LEAF_AXIS) for r in rows)
             stats = tuple(
                 jax.lax.psum(s, LEAF_AXIS)
                 for s in ((losses * w_l).sum(), (nrms * w_l).sum(),
-                          (clips.astype(jnp.float32) * w_l).sum(),
-                          w_l.sum()))
+                          (clips * w_l).sum(), w_l.sum()))
             return acc, stats
 
         args = [params, batch, rngs, weights]
@@ -401,14 +384,13 @@ def build_sharded_round_step(loss_fn: Callable, fl_cfg, *, cohort_size: int,
         if masked:
             args.append(skey)
             in_specs.append(P())
-        acc, (loss_s, norm_s, clip_s, w_s) = shard_map(
+        acc, (loss_s, norm_s, clip_s, w_s) = jax.shard_map(
             leaf_fn, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=(P(), (P(), P(), P(), P())), check_rep=False,
+            out_specs=(P(), (P(), P(), P(), P())), check_vma=False,
         )(*args)
 
         w_total = jnp.maximum(w_s, 1e-9)
-        mean_delta = agg.finalize_aggregate(acc, w_s, spec,
-                                            jax.random.fold_in(rng, 0xDEE))
+        mean_delta = _finalize(acc, w_s, spec, plan, rng)
         new_params, new_opt = server.apply(params, state.opt_state,
                                            mean_delta)
         metrics = {

@@ -175,6 +175,8 @@ def pack_residues(q: jnp.ndarray, modulus: int) -> jnp.ndarray:
     row — same byte count, no-op reduction).
     """
     bits = wire_bits(modulus)
+    if bits == 32:  # one residue per word: the row's own bits
+        return jax.lax.bitcast_convert_type(q.astype(jnp.int32), jnp.uint32)
     size = q.shape[-1]
     nwords = packed_words(size, modulus)
     mask = jnp.uint32((1 << bits) - 1)
@@ -211,6 +213,8 @@ def unpack_residues(words: jnp.ndarray, size: int,
             f"{size} residues of a {modulus}-modulus field "
             f"({bits}-bit wire -> {nwords} words); was this row packed "
             f"under a different session field?")
+    if bits == 32:
+        return jax.lax.bitcast_convert_type(words, jnp.int32)
     mask = jnp.uint32((1 << bits) - 1)
     groups = -(-size // 32)
     pad = groups * bits - nwords

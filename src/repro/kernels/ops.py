@@ -20,6 +20,9 @@ from repro.kernels import secure_agg as _sa
 
 
 def on_tpu() -> bool:
+    """The one backend decision of the repo: on a TPU the Pallas kernels
+    compile natively; anywhere else (the CPU test suite) they run in
+    interpret mode."""
     return jax.default_backend() == "tpu"
 
 

@@ -19,8 +19,8 @@ kernels in kernels/secure_agg.py):
   pair key   (pk0, pk1) = threefry(session_key, (lo, hi))
   element e  word       = threefry(pair_key,    (e >> 1, tag))[e & 1]
 
-Two consecutive elements share one Threefry evaluation (each evaluation
-yields two 32-bit lanes), which halves host-side generation cost; the ``tag``
+Two consecutive elements share one Threefry counter (each evaluation
+yields two 32-bit lanes, and the element's parity picks one); the ``tag``
 word separates independent stream families drawn from one key (masks vs
 stochastic-rounding uniforms).
 """
@@ -105,26 +105,23 @@ def stream_at(pk0, pk1, e, *, tag: int = TAG_MASK,
 def stream_block(pk0, pk1, length: int, *, tag: int = TAG_MASK,
                  offset: int = 0,
                  rounds: int = DEFAULT_ROUNDS) -> jnp.ndarray:
-    """The host fast path: ``stream_at(offset + arange(length))`` at half cost.
+    """The host path: ``stream_at(offset + arange(length))``.
 
-    One Threefry evaluation per TWO elements (both lanes used).  ``pk0/pk1``
-    may carry leading batch dims; the stream axis is appended last.
-    ``offset`` shifts the element positions, so a chunk of a longer stream
-    (a ``ParamPlan`` chunk's slice of the model-wide uniform stream) is
-    bit-identical to the corresponding slice of the full block.
+    ``pk0/pk1`` may carry leading batch dims; the stream axis is appended
+    last.  ``offset`` shifts the element positions, so a chunk of a longer
+    stream (a ``ParamPlan`` chunk's slice of the model-wide uniform stream)
+    is bit-identical to the corresponding slice of the full block.
+
+    Every element evaluates its own counter and keeps the lane its parity
+    selects.  Interleaving the two lanes of one evaluation instead would
+    halve the Threefry work, but the (n, 2) pairs it stacks are laid out
+    on a TPU as lane-padded tiles 64x their size — at model widths that
+    alone exceeds the chip's memory.
     """
-    pk0 = jnp.asarray(pk0).astype(U32)
-    pk1 = jnp.asarray(pk1).astype(U32)
-    lo = offset >> 1
-    n = ((offset + length + 1) >> 1) - lo  # counters covering the window
-    c = U32(lo) + jnp.arange(n, dtype=U32)
-    c = c.reshape((1,) * pk0.ndim + (n,))
-    tags = jnp.full_like(c, U32(tag))
-    y0, y1 = threefry2x32(pk0[..., None], pk1[..., None], c, tags,
-                          rounds=rounds)
-    out = jnp.stack([y0, y1], axis=-1).reshape(pk0.shape + (2 * n,))
-    start = offset & 1
-    return out[..., start:start + length].astype(jnp.int32)
+    pk0 = jnp.asarray(pk0).astype(U32)[..., None]
+    pk1 = jnp.asarray(pk1).astype(U32)[..., None]
+    e = U32(offset) + jnp.arange(length, dtype=U32)
+    return stream_at(pk0, pk1, e, tag=tag, rounds=rounds)
 
 
 def uniform_block(uk0, uk1, length: int, *, offset: int = 0,
@@ -140,6 +137,10 @@ def uniform_block(uk0, uk1, length: int, *, offset: int = 0,
 
 
 def bits_to_uniform(bits: jnp.ndarray) -> jnp.ndarray:
-    """int32 PRF words -> f32 uniforms in [0, 1) (top 24 bits, exact)."""
-    return (bits.astype(U32) >> U32(8)).astype(jnp.float32) * jnp.float32(
-        2.0 ** -24)
+    """int32 PRF words -> f32 uniforms in [0, 1) (top 24 bits, exact).
+
+    The 24-bit value goes through int32 on its way to f32: it is exact in
+    both, and Mosaic has no uint32 -> f32 conversion.
+    """
+    top = (bits.astype(U32) >> U32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(2.0 ** -24)

@@ -187,7 +187,7 @@ def rotate_quantize_prf(x: jnp.ndarray, scale: float, op_key_words,
     Deliberately an independent formulation — the ±1 diagonal one
     TAG_SIGN word per position, the Walsh–Hadamard butterfly as explicit
     per-element GATHERS (each stage reads its two operands by index
-    arithmetic rather than the reshape cascade the kernel and
+    arithmetic rather than the lane rotations the kernel and
     ``core.fl.compression.fwht`` both use; the per-element float ops are
     the same single add/sub, so the result is bit-identical while the
     indexing is derived independently), stochastic-rounding uniforms one

@@ -11,17 +11,41 @@ are NOT in cost_analysis); wire factors: all-reduce 2x (ring = reduce-scatter
 + all-gather), all-gather / reduce-scatter / all-to-all / collective-permute
 1x of the result-shard size.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (one link per transfer assumed: conservative).
+Hardware constants come from :data:`PEAKS`, keyed by ``device_kind``; the
+production mesh (``launch/mesh.py``) is a TPU v5e, :data:`TARGET_KIND`.
 """
 from __future__ import annotations
 
 import re
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s int8,
+# 16 GB HBM at 819 GB/s).  The ICI figure is per LINK and an assumption
+# (one ~50 GB/s link per transfer, conservative), not a published peak.
+# A kind missing here is an error, never a default.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,
+        "ops_int8": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "ici_link_bytes_per_s": 50e9,
+    },
+}
+TARGET_KIND = "TPU v5 lite"  # the chip of make_production_mesh
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table of one chip kind; raises for a kind not in it."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} (known: "
+            f"{sorted(PEAKS)}); add its figures to PEAKS with their "
+            f"source") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -96,8 +120,10 @@ def collective_summary(hlo_text: str, loop_multiplier: float = 1.0) -> Dict:
 
 
 def roofline(compiled, hlo_text: str, *, model_flops: float = 0.0,
-             chips: int = 1, multiplier: float = 1.0) -> Dict:
-    """Three-term roofline from a compiled executable.
+             chips: int = 1, multiplier: float = 1.0,
+             device_kind: str = TARGET_KIND) -> Dict:
+    """Three-term roofline from a compiled executable, against the peaks
+    of ``device_kind`` (default: the production mesh's chip).
 
     model_flops: analytic 6*N*D (or 6*N_active*D) *global* FLOPs — compared
     against per-device HLO flops x chips for the usefulness ratio.
@@ -117,9 +143,10 @@ def roofline(compiled, hlo_text: str, *, model_flops: float = 0.0,
             "total_wire_bytes": coll["total_wire_bytes"] * multiplier,
         }
 
-    t_compute = flops / PEAK_FLOPS
-    t_memory = bytes_accessed / HBM_BW
-    t_collective = coll["total_wire_bytes"] / ICI_BW
+    p = peaks(device_kind)
+    t_compute = flops / p["flops_bf16"]
+    t_memory = bytes_accessed / p["hbm_bytes_per_s"]
+    t_collective = coll["total_wire_bytes"] / p["ici_link_bytes_per_s"]
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_collective}
     dominant = max(terms, key=terms.get)
 

@@ -39,13 +39,13 @@ def main(argv=None):
     from repro.configs import registry
     from repro.configs.shapes import SHAPES
     from repro.launch.lowering import lower_pair
-    from repro.launch.mesh import make_mesh_compat, make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
 
     def get_mesh(multi_pod):
         if args.mesh:
             dims = tuple(int(x) for x in args.mesh.split(","))
             names = ("pod", "data", "model")[-len(dims):]
-            return make_mesh_compat(dims, names)
+            return make_mesh(dims, names)
         return make_production_mesh(multi_pod=multi_pod)
 
     pairs = []

@@ -201,8 +201,8 @@ def _probe_train(cfg, shape, mesh, fl_cfg, opts, meta):
     popts["clients_per_chunk"] = probe_shape.global_batch
     if meta["client_parallel"]:
         tp = mesh.shape["model"]
-        from repro.launch.mesh import make_mesh_compat
-        probe_mesh = make_mesh_compat((1, tp), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        probe_mesh = make_mesh((1, tp), ("data", "model"))
     else:
         probe_mesh = mesh
     fn, args, _ = build_train(probe_cfg, probe_shape, probe_mesh,
@@ -278,7 +278,8 @@ def lower_pair(arch: str, shape_name: str, mesh, *, reduced: bool = False,
                         "count": full_coll["count"],
                         "probe_tp_only": probe_coll,
                     }
-                    out["roofline"]["t_collective_s"] = wire / analysis.ICI_BW
+                    out["roofline"]["t_collective_s"] = wire / analysis.peaks(
+                        analysis.TARGET_KIND)["ici_link_bytes_per_s"]
                     terms = {"compute": out["roofline"]["t_compute_s"],
                              "memory": out["roofline"]["t_memory_s"],
                              "collective": out["roofline"]["t_collective_s"]}

@@ -5,30 +5,22 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """Version-compatible ``jax.make_mesh``.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist on newer jax;
-    on e.g. 0.4.37 plain ``make_mesh`` already yields Auto axes, so simply
-    omit the argument when the enum is absent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` over the visible devices with Auto (GSPMD) axes."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e: 16x16 (256 chips) per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2):
     """Small mesh over host devices for CPU integration tests."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 LEAF_AXIS = "leaf"
@@ -51,7 +43,7 @@ def make_agg_mesh(num_leaves: int, devices=None):
                 f"aggregation tier wants {num_leaves} leaves but only "
                 f"{len(avail)} devices are visible (force host devices with "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-        return make_mesh_compat((num_leaves,), (LEAF_AXIS,))
+        return make_mesh((num_leaves,), (LEAF_AXIS,))
     import numpy as np
     return jax.sharding.Mesh(np.asarray(devices).reshape(num_leaves),
                              (LEAF_AXIS,))

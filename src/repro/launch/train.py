@@ -49,6 +49,9 @@ def main(argv=None):
     from repro.configs.base import FLConfig
     from repro.core.fl.accountant import RDPAccountant
     from repro.core.fl.round import build_round_step, init_fl_state
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     fl_cfg = FLConfig(
         cohort_size=args.cohort, local_steps=args.local_steps,

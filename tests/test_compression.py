@@ -153,6 +153,20 @@ def test_operators_are_unbiased(cmode):
     assert np.all(np.abs(mean - np.asarray(x)) < 6.0 * sem + 1e-4)
 
 
+@pytest.mark.parametrize("n,m,tie_every", [(1000, 250, 7), (777, 300, 3),
+                                            (4096, 4096, 0), (5000, 1, 2)])
+def test_smallest_m_equals_stable_argsort_selection(n, m, tie_every):
+    """The sort-free selection keeps exactly ``sort(argsort(ranks)[:m])``,
+    ties at the threshold taken in position order."""
+    ranks = jax.random.randint(jax.random.PRNGKey(n), (n,), -2 ** 31,
+                               2 ** 31 - 1, jnp.int32)
+    if tie_every:
+        ranks = ranks.at[::tie_every].set(ranks[1])
+    want = np.sort(np.argsort(np.asarray(ranks), kind="stable")[:m])
+    got = np.asarray(jax.jit(comp.smallest_m, static_argnums=1)(ranks, m))
+    np.testing.assert_array_equal(got, want)
+
+
 def test_sketch_rotation_is_orthonormal_and_self_inverse():
     x = jax.random.normal(jax.random.PRNGKey(1), (1024,))
     op = comp.chunk_operators(jax.random.PRNGKey(2), "sketch", 1024, 1.0)

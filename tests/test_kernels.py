@@ -258,3 +258,74 @@ def test_rotate_quantize_prf_kernel_oracle_host_three_way(D, off):
     u = prf.uniform_block(*prf.key_words(u_key), full, offset=off)
     host = (floor + (u < (y - floor)).astype(jnp.float32)).astype(jnp.int32)
     assert bool(jnp.all(got == host))
+
+
+# --- a vmap over an SMEM-meta kernel folds into its batch grid axis ---------
+_NB = jnp.asarray([[1, 2], [0, 3], [3, 0], [2, 1], [5, 4], [4, 5]], jnp.int32)
+
+
+def _qmp(x, uk, slot, mk, nbrs):
+    sess = ksa.SessionMeta(key_words=mk, num_slots=6, degree=2,
+                           neighbors=nbrs)
+    return ksa.quantize_mask_prf(x, 1e3, slot, uk, sess, u_offset=17,
+                                 block=1024, interpret=True)
+
+
+def _accum(x, w, u, mk, off):
+    sess = ksa.SessionMeta(key_words=mk, num_slots=10, degree=2,
+                           slot_offset=off)
+    return ksa.weighted_quantize_accum(x, w, u, 1e3, session=sess,
+                                       interpret=True)
+
+
+def _vmap_cases():
+    """name -> (function, in_axes per nesting level (outer first), args)."""
+    key = jax.random.PRNGKey(0)
+    x = jax.random.normal(key, (2, 3, 2000))
+    uk = jax.random.randint(key, (2, 3, 2), 0, 2**31 - 1).astype(jnp.uint32)
+    mk = jnp.asarray([[5, 7], [9, 11]], jnp.uint32)
+    slots = jnp.arange(6, dtype=jnp.int32).reshape(2, 3)
+    xa = jax.random.normal(key, (2, 5, 2000))
+    ua = jax.random.uniform(key, (2, 5, 2000))
+    return {
+        # clients x rows, every scalar per element, a shared graph table
+        "nested_random_graph": (
+            _qmp, [(0, 0, 0, None, None), (0, 0, 0, None, None)],
+            (x, uk, slots, mk[0], _NB)),
+        # per-leaf sessions: the key varies over the outer axis only
+        "per_leaf_session_keys": (
+            _qmp, [(0, 0, 0, 0, None), (0, 0, 0, None, None)],
+            (x, uk, slots, mk, _NB)),
+        # a meta batched inside, shared across the outer axis
+        "inner_only_meta": (
+            _qmp, [(0, None, None, None, None), (0, 0, 0, None, None)],
+            (x, uk[0], slots[0], mk[0], _NB)),
+        # a meta shared inside, batched across the outer axis
+        "outer_only_meta": (
+            _qmp, [(0, 0, 0, None, None), (0, None, None, None, None)],
+            (x, uk[:, 0], slots[:, 0], mk[0], _NB)),
+        "rotate": (
+            lambda x, uk: ksa.rotate_quantize_prf(
+                x, 1e3, jnp.asarray([9, 11], jnp.uint32), uk, u_offset=5,
+                block=1024, interpret=True),
+            [(0, 0), (0, 0)], (x, uk)),
+        "accum_session_shards": (
+            _accum, [(None, None, None, None, 0), (0, 0, 0, 0, None)],
+            (xa, jnp.ones((2, 5)), ua, mk, jnp.asarray([0, 5], jnp.int32))),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_vmap_cases()))
+def test_smem_kernels_vmap_equals_per_element_calls(name):
+    fn, (outer, inner), args = _vmap_cases()[name]
+    got = np.asarray(jax.jit(jax.vmap(jax.vmap(fn, in_axes=inner),
+                                      in_axes=outer))(*args))
+
+    def pick(a, ax, i):
+        return a if ax is None else a[i]
+
+    for i in range(got.shape[0]):
+        a1 = [pick(a, ax, i) for a, ax in zip(args, outer)]
+        for j in range(got.shape[1]):
+            a2 = [pick(a, ax, j) for a, ax in zip(a1, inner)]
+            np.testing.assert_array_equal(got[i, j], np.asarray(fn(*a2)))

@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from repro.testing.hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.fl import secure_agg as sa
 

@@ -98,3 +98,11 @@ def test_roofline_terms_math():
     assert r["t_collective_s"] == pytest.approx(2 * 5e8 / 50e9)
     assert r["dominant"] in ("compute", "memory")
     assert r["useful_flops_ratio"] == pytest.approx(1.0)
+
+
+def test_peaks_are_keyed_by_device_kind():
+    """The v5e's published peaks; a kind missing from the table raises."""
+    p = analysis.peaks("TPU v5 lite")
+    assert (p["flops_bf16"], p["hbm_bytes_per_s"]) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        analysis.peaks("cpu")
