@@ -1,0 +1,9 @@
+"""Device: 1 - (union of device-busy intervals) / traced window, averaged
+over the chips of the cell."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
