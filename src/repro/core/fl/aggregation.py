@@ -816,19 +816,36 @@ def encode_plan_flat(xs: Sequence[jnp.ndarray], weight, slot,
     Returns (tuple of PADDED (wire_padded_c,) int32 rows, pre-clip norm,
     was_clipped).
     """
-    sq = plan_sq_norms(plan, xs)
-    nrm = jnp.sqrt(sq)
-    clip_scale = jnp.minimum(1.0, spec.clip_norm / jnp.maximum(nrm, 1e-12))
-    weight = jnp.asarray(weight, jnp.float32)
+    with jax.named_scope("clip"):
+        sq = plan_sq_norms(plan, xs)
+        nrm = jnp.sqrt(sq)
+        clip_scale = jnp.minimum(1.0,
+                                 spec.clip_norm / jnp.maximum(nrm, 1e-12))
+        weight = jnp.asarray(weight, jnp.float32)
+        xws = []
+        for c, x in enumerate(xs):
+            xw = x * (weight * clip_scale)
+            if spec.dev_noise > 0.0:
+                noise = jax.random.normal(plan.chunk_noise_key(rng, c),
+                                          x.shape, jnp.float32)
+                xw = xw + noise * (spec.dev_noise * weight)
+            xws.append(xw)
+    with jax.named_scope("quantize_mask"):
+        rows = _quantize_mask_chunks(xws, slot, spec, plan, sessions, rng,
+                                     masked=masked, use_pallas=use_pallas,
+                                     ops=ops)
+    return rows, nrm, (clip_scale < 1.0).astype(jnp.float32)
+
+
+def _quantize_mask_chunks(xws, slot, spec: AggregationSpec, plan: ParamPlan,
+                          sessions, rng, *, masked: bool, use_pallas: bool,
+                          ops):
+    """The quantize + mask half of :func:`encode_plan_flat`: each clipped,
+    weighted chunk to its PADDED int32 wire row."""
     u_words = prf.key_words(jax.random.fold_in(rng, 2))
     wire = plan_wire_chunks(spec, plan) if ops is not None else plan.chunks
     rows = []
-    for c, (ck, x) in enumerate(zip(plan.chunks, xs)):
-        xw = x * (weight * clip_scale)
-        if spec.dev_noise > 0.0:
-            noise = jax.random.normal(plan.chunk_noise_key(rng, c), x.shape,
-                                      jnp.float32)
-            xw = xw + noise * (spec.dev_noise * weight)
+    for c, (ck, xw) in enumerate(zip(plan.chunks, xws)):
         if ops is not None:
             op, wc = ops[c], wire[c]
             if op.mode == "sketch" and use_pallas:
@@ -873,7 +890,7 @@ def encode_plan_flat(xs: Sequence[jnp.ndarray], weight, slot,
         if ck.padded > ck.size:
             row = jnp.pad(row, (0, ck.padded - ck.size))
         rows.append(row)
-    return tuple(rows), nrm, (clip_scale < 1.0).astype(jnp.float32)
+    return tuple(rows)
 
 
 def encode_plan_contribution(delta, weight, slot, spec: AggregationSpec,
@@ -904,18 +921,22 @@ def aggregate_plan_masked_buffer(bufs: Sequence[jnp.ndarray],
     wire = plan_wire_chunks(spec, plan)
     accs = []
     for c, (wc, mbuf) in enumerate(zip(wire, bufs)):
-        if recover:
-            acc = jnp.sum(mbuf * pres_i[:, None], axis=0)  # mod 2^32
-            if masked:
+        with jax.named_scope("sum"):
+            if recover:
+                acc = jnp.sum(mbuf * pres_i[:, None], axis=0)  # mod 2^32
+            else:  # full session: masks cancel exactly
+                acc = jnp.sum(mbuf, axis=0)
+        if recover and masked:
+            with jax.named_scope("recover"):
                 rec = sessions[c].recovery((wc.size,), present)
                 if wc.padded > wc.size:
                     rec = jnp.pad(rec, (0, wc.padded - wc.size))
                 acc = acc + rec
-        else:
-            acc = jnp.sum(mbuf, axis=0)  # full session: masks cancel exactly
         accs.append(acc)
-    return finalize_plan_aggregate(accs, total_weight, spec, plan,
-                                   jax.random.fold_in(rng, 0xDEE), ops=ops)
+    with jax.named_scope("apply"):
+        return finalize_plan_aggregate(accs, total_weight, spec, plan,
+                                       jax.random.fold_in(rng, 0xDEE),
+                                       ops=ops)
 
 
 def plan_buffer_noise_and_uniforms(rng, B: int, spec: AggregationSpec,

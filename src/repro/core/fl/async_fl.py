@@ -201,7 +201,8 @@ def build_masked_async_buffer_step(params, fl_cfg, *, buffer_size: int,
         mean_delta = agg.aggregate_plan_masked_buffer(
             mbufs, present, w_total, spec, plan, sessions, rng,
             recover=recover, masked=masked, ops=ops)
-        new_params, new_opt = server.apply(params, opt_state, mean_delta)
+        with jax.named_scope("apply"):
+            new_params, new_opt = server.apply(params, opt_state, mean_delta)
         metrics["staleness_mean"] = agg.ordered_sum(staleness * present) \
             / jnp.maximum(agg.ordered_sum(present), 1.0)
         return new_params, new_opt, metrics
@@ -435,24 +436,27 @@ class AsyncServer:
                 against its own mask session — the full (D,) concatenation
                 is never formed.
                 """
-                w = staleness_weight(s, s_mode, s_exp)
-                sessions = (agg.plan_sessions(spec, plan, session_key)
-                            if masked else None)
-                ops = agg.plan_operators(spec, plan, session_key)
-                rows, nrm, clipped = agg.encode_plan_contribution(
-                    delta, w, slot, spec, plan, sessions, rng,
-                    masked=masked, use_pallas=use_pallas, ops=ops)
+                with jax.named_scope("encode"):
+                    w = staleness_weight(s, s_mode, s_exp)
+                    sessions = (agg.plan_sessions(spec, plan, session_key)
+                                if masked else None)
+                    ops = agg.plan_operators(spec, plan, session_key)
+                    rows, nrm, clipped = agg.encode_plan_contribution(
+                        delta, w, slot, spec, plan, sessions, rng,
+                        masked=masked, use_pallas=use_pallas, ops=ops)
                 return rows, w, nrm, clipped
 
             @jax.jit
             def _write_row(bufs, stal, wts, norms, clips, slot, rows, s, w,
                            nrm, clipped):
                 """SERVER-side jit: store one masked row (all chunks)."""
-                return (tuple(b.at[slot].set(r) for b, r in zip(bufs, rows)),
-                        stal.at[slot].set(jnp.asarray(s, jnp.float32)),
-                        wts.at[slot].set(w),
-                        norms.at[slot].set(nrm),
-                        clips.at[slot].set(clipped))
+                with jax.named_scope("store"):
+                    return (tuple(b.at[slot].set(r)
+                                  for b, r in zip(bufs, rows)),
+                            stal.at[slot].set(jnp.asarray(s, jnp.float32)),
+                            wts.at[slot].set(w),
+                            norms.at[slot].set(nrm),
+                            clips.at[slot].set(clipped))
 
             @jax.jit
             def _wire_pack(rows, session_key):
@@ -488,10 +492,12 @@ class AsyncServer:
 
             @jax.jit
             def _write(bufs, stal, valid, slot, delta, s):
-                rows = plan.chunk_arrays(delta, pad=True)
-                return (tuple(b.at[slot].set(r) for b, r in zip(bufs, rows)),
-                        stal.at[slot].set(jnp.asarray(s, jnp.float32)),
-                        valid.at[slot].set(1.0))
+                with jax.named_scope("store"):
+                    rows = plan.chunk_arrays(delta, pad=True)
+                    return (tuple(b.at[slot].set(r)
+                                  for b, r in zip(bufs, rows)),
+                            stal.at[slot].set(jnp.asarray(s, jnp.float32)),
+                            valid.at[slot].set(1.0))
 
             self._write = _write
 
@@ -598,12 +604,15 @@ class AsyncServer:
                           self._spec.compression)
 
     def _encode_for_slot(self, delta, staleness, slot: int, rng=None):
-        """One masked encode bound to (current session, ``slot``)."""
-        if rng is None:
-            rng = jax.random.fold_in(
-                jax.random.fold_in(self._push_base, self.version), slot)
-        return self._masked_encode(delta, slot, staleness,
-                                   self._session_key(), rng)
+        """One masked encode bound to (current session, ``slot``): the
+        ``encode`` span covers the session key, the per-slot rng and the
+        encode's dispatch."""
+        with self._span("encode", slot=slot):
+            if rng is None:
+                rng = jax.random.fold_in(
+                    jax.random.fold_in(self._push_base, self.version), slot)
+            return self._masked_encode(delta, slot, staleness,
+                                       self._session_key(), rng)
 
     def push_encoded(self, cp: ClientPush, rng=None):
         """The SERVER half of mask_mode='client': store one masked row.
@@ -672,19 +681,26 @@ class AsyncServer:
 
     def _store_row(self, slot: int, row, staleness, w, nrm, clipped,
                    rng=None) -> None:
-        """Write one masked row into its session slot (+ apply when full)."""
+        """Write one masked row into its session slot (+ apply when full).
+
+        The ``store`` span covers the write's dispatch and the slot
+        bookkeeping, and closes before the apply (``decode``) opens."""
         rows = row if isinstance(row, tuple) else (row,)
-        (self._bufs, self._stal, self._wts, self._norms,
-         self._clips) = self._write_row(
-            self._bufs, self._stal, self._wts, self._norms, self._clips,
-            slot, rows, staleness, w, nrm, clipped)
+        with self._span("store", slot=slot):
+            (self._bufs, self._stal, self._wts, self._norms,
+             self._clips) = self._write_row(
+                self._bufs, self._stal, self._wts, self._norms, self._clips,
+                slot, rows, staleness, w, nrm, clipped)
+            self._mark_stored(slot)
+        if self._fill >= self.buffer_size:
+            self._apply(rng)
+
+    def _mark_stored(self, slot: int) -> None:
         self._present[slot] = True
         self._fill += 1
         self.telemetry.count("stored_contributions", **self._tl)
         self.telemetry.gauge("buffered_contributions", self._fill,
                              **self._tl)
-        if self._fill >= self.buffer_size:
-            self._apply(rng)
 
     def push(self, delta, client_version: int, rng=None,
              slot: Optional[int] = None, push_id: Optional[int] = None):
@@ -759,14 +775,11 @@ class AsyncServer:
             return True
         if slot is None:
             slot = self._present.index(False)
-        self._bufs, self._stal, self._valid = self._write(
-            self._bufs, self._stal, self._valid, slot, delta,
-            staleness)
-        self._present[slot] = True
-        self._fill += 1
-        self.telemetry.count("stored_contributions", **self._tl)
-        self.telemetry.gauge("buffered_contributions", self._fill,
-                             **self._tl)
+        with self._span("store", slot=slot):
+            self._bufs, self._stal, self._valid = self._write(
+                self._bufs, self._stal, self._valid, slot, delta,
+                staleness)
+            self._mark_stored(slot)
         if self._fill >= self.buffer_size:
             self._apply(rng)
         return True

@@ -470,9 +470,10 @@ def build_two_level_masked_step(params, fl_cfg, *, num_leaves: int,
                 return tuple(accs)
 
             accs = jax.vmap(one_leaf)(gleaves, rows_b, pres_b)
-            return jax.lax.psum(
-                jax.tree.map(lambda a: jnp.sum(a, axis=0, dtype=a.dtype),
-                             accs), LEAF_AXIS)
+            with jax.named_scope("combine"):
+                return jax.lax.psum(
+                    jax.tree.map(lambda a: jnp.sum(a, axis=0, dtype=a.dtype),
+                                 accs), LEAF_AXIS)
 
         accs = shard_map(
             dev_fn, mesh=mesh,
@@ -846,10 +847,11 @@ class ShardedAsyncServer:
                     def per_leaf(a):
                         return a.reshape((lpd, kb) + a.shape[1:])
 
-                    rows_e, w, nrm, cl = jax.vmap(
-                        lambda r, s, t: encode_row(r, s, t, skey, pkey))(
-                        tuple(flat(r) for r in routed_b), gslots,
-                        flat(stals_b))
+                    with jax.named_scope("encode"):
+                        rows_e, w, nrm, cl = jax.vmap(
+                            lambda r, s, t: encode_row(r, s, t, skey, pkey))(
+                            tuple(flat(r) for r in routed_b), gslots,
+                            flat(stals_b))
 
                     def one_leaf(buf_l, wts_l, norms_l, clips_l, stal_l,
                                  rows_l, w_l, nrm_l, cl_l, sl, vld, st):
@@ -861,11 +863,12 @@ class ShardedAsyncServer:
                                 clips_l.at[tgt].set(cl_l, mode="drop"),
                                 stal_l.at[tgt].set(st, mode="drop"))
 
-                    return jax.vmap(one_leaf)(
-                        buf_b, wts_b, norms_b, clips_b, stal_b,
-                        tuple(per_leaf(r) for r in rows_e), per_leaf(w),
-                        per_leaf(nrm), per_leaf(cl), lslot_b, valid_b,
-                        stals_b)
+                    with jax.named_scope("store"):
+                        return jax.vmap(one_leaf)(
+                            buf_b, wts_b, norms_b, clips_b, stal_b,
+                            tuple(per_leaf(r) for r in rows_e), per_leaf(w),
+                            per_leaf(nrm), per_leaf(cl), lslot_b, valid_b,
+                            stals_b)
 
                 return shard_map(
                     dev_fn, mesh=self.mesh,

@@ -12,6 +12,8 @@ observe flows through here:
     context managers with parent/child nesting and an optional
     ``jax.block_until_ready`` fence (``sp.fence(out)``) so asynchronously
     dispatched device work is attributed to the span that launched it.
+    A recorded span also writes a ``jax.profiler.TraceAnnotation`` of its
+    name, so it shows in a profiler trace.
   * **the de-identification gate** — every label key and string value
     passes :func:`repro.core.funnel_logging.scrub_label` (the paper's
     §Logging contract): forbidden key vocabulary AND identifier-shaped
@@ -111,7 +113,12 @@ def _block_until_ready(value) -> None:
 
 
 class _Span:
-    __slots__ = ("_tel", "name", "labels", "sid", "parent", "_t0", "_fence")
+    """A recorded span.  It also enters ``jax.profiler.TraceAnnotation``
+    under its name, so a profiler trace (TensorBoard, Perfetto) shows the
+    span on the host thread beside the device ops, on the trace's clock."""
+
+    __slots__ = ("_tel", "name", "labels", "sid", "parent", "_t0", "_fence",
+                 "_annotation")
 
     def __init__(self, tel: "Telemetry", name: str,
                  labels: Dict[str, Any]):
@@ -133,6 +140,9 @@ class _Span:
         self.sid = tel._next_sid
         tel._next_sid += 1
         tel._stack.append(self.sid)
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -140,6 +150,7 @@ class _Span:
         if self._fence is not None and self._tel.fence:
             _block_until_ready(self._fence)
         dur = time.perf_counter_ns() - self._t0
+        self._annotation.__exit__(*exc)
         tel = self._tel
         if tel._stack and tel._stack[-1] == self.sid:
             tel._stack.pop()

@@ -64,6 +64,14 @@ class SessionMeta(NamedTuple):
     neighbors: Any = None
 
 
+def _kernel_name(kern) -> str:
+    """The name a ``pallas_call`` carries into the compiled program and the
+    profiler trace: its kernel's, ``partial(_quantize_mask_prf_kernel, ...)``
+    -> ``quantize_mask_prf``."""
+    fn = getattr(kern, "func", kern)
+    return fn.__name__.strip("_").removesuffix("_kernel")
+
+
 def _pad1(x: jnp.ndarray, mult: int) -> jnp.ndarray:
     p = (-x.shape[-1]) % mult
     return x if p == 0 else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, p)])
@@ -94,6 +102,7 @@ def quantize_mask(x: jnp.ndarray, mask: jnp.ndarray, uniforms: jnp.ndarray,
                              value_range=value_range)
     out = pl.pallas_call(
         kern,
+        name=_kernel_name(kern),
         grid=(x.shape[0] // block,),
         in_specs=[pl.BlockSpec((block,), lambda i: (i,))] * 3,
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
@@ -254,6 +263,7 @@ def _row_tile_call(kern, x, smem, rb: int, interpret: bool):
     tile = pl.BlockSpec((pl.squeezed, rb, ROW), lambda b, i: (b, i, 0))
     return pl.pallas_call(
         kern,
+        name=_kernel_name(kern),
         grid=(B, rows // rb),
         in_specs=[tile] + [_smem_spec()] * len(smem),
         out_specs=tile,
@@ -561,6 +571,7 @@ def _session_accum_call(x, weights, uniforms, meta, *table, scale,
     c_spec = pl.BlockSpec((sq, block_c, 1), lambda b, j, i: (b, i, 0))
     return pl.pallas_call(
         kern,
+        name=_kernel_name(kern),
         grid=(B, Dp // block_d, Cp // block_c),
         in_specs=[cd_spec, c_spec, cd_spec] + [_smem_spec()] * len(smem),
         out_specs=pl.BlockSpec((sq, 1, block_d), lambda b, j, i: (b, 0, j)),
@@ -646,6 +657,7 @@ def weighted_quantize_accum(x: jnp.ndarray, weights: jnp.ndarray,
         in_specs, args = [cd_spec, c_spec, cd_spec], (x, weights, uniforms)
     out = pl.pallas_call(
         kern,
+        name=_kernel_name(kern),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_d), lambda j, i: (0, j)),
@@ -718,6 +730,7 @@ def pack_residues(q: jnp.ndarray, bits: int, *,
     kern = functools.partial(_pack_residues_kernel, bits=bits)
     out = pl.pallas_call(
         kern,
+        name=_kernel_name(kern),
         grid=(rows // rb,),
         in_specs=[pl.BlockSpec((rb, 32, LANES), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((rb, bits, LANES), lambda i: (i, 0, 0)),
@@ -761,6 +774,7 @@ def unpack_residues(words: jnp.ndarray, size: int, bits: int, *,
     kern = functools.partial(_unpack_residues_kernel, bits=bits)
     out = pl.pallas_call(
         kern,
+        name=_kernel_name(kern),
         grid=(rows // rb,),
         in_specs=[pl.BlockSpec((rb, bits, LANES), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((rb, 32, LANES), lambda i: (i, 0, 0)),
@@ -782,6 +796,7 @@ def dequantize(q: jnp.ndarray, scale: float, *, block: int = DEFAULT_BLOCK,
     kern = functools.partial(_dequantize_kernel, inv_scale=1.0 / scale)
     out = pl.pallas_call(
         kern,
+        name=_kernel_name(kern),
         grid=(qp.shape[0] // block,),
         in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),

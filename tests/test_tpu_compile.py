@@ -13,6 +13,7 @@ tests and only the worker given this file loads the TPU compiler.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -134,3 +135,28 @@ def test_kernel_compiles_for_v5e(name, one_chip, width):
     compiled = jax.jit(fn).lower(*args).compile()
     # one kernel launch per case: a vmap adds a grid axis, never a launch
     assert compiled.as_text().count(KERNEL) == 1
+
+
+# case -> the name its kernel launch carries (the kernel function's, with
+# the leading underscore and the ``_kernel`` suffix dropped)
+NAMED = {
+    "quantize_mask_prf_ring": "quantize_mask_prf",
+    "weighted_quantize_accum_session_ring":
+        "prf_masked_weighted_quantize_accum",
+    "pack_residues_19bit": "pack_residues",
+    "unpack_residues_19bit": "unpack_residues",
+    "rotate_quantize_prf": "rotate_quantize_prf",
+    "dequantize": "dequantize",
+}
+
+
+@pytest.mark.parametrize("name", sorted(NAMED))
+def test_kernel_launch_carries_its_kernel_name(name, one_chip):
+    """The compiled kernel launch is named after its kernel, so a trace's
+    device ops read ``<program>/<kernel>.<n>``.  A small width: the name
+    does not depend on it."""
+    fn, shapes = _cases(4096)[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert re.search(rf"%{NAMED[name]}(\.\d+)? = [^\n]*{KERNEL}", text)
