@@ -25,6 +25,8 @@ network overhead ~8x versus synchronous rounds.  This module provides:
 """
 from __future__ import annotations
 
+import collections
+import functools
 import heapq
 import math
 from dataclasses import dataclass
@@ -32,6 +34,7 @@ from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import telemetry as tele
 from repro.core.fl import aggregation as agg
@@ -45,6 +48,14 @@ from repro.kernels.ops import on_tpu
 FAULT_METRIC_KEYS = ("duplicate_pushes", "rejected_pushes",
                      "subquorum_deferrals", "lost_contributions",
                      "released_updates")
+
+# Stores that may be unfinished on the device when the host dispatches the
+# next one.  The store donates the buffer, so no allocator wait for a buffer
+# copy paces the host any more: unbounded, it would dispatch a whole session
+# ahead of the device, and a session's last arrival would wait behind that
+# backlog.  Two keeps one write running and one queued, so the device never
+# waits on the host.  A property of the store, not a deployment setting.
+_ROWS_IN_FLIGHT = 2
 
 
 def batch_count(delta, params) -> Optional[int]:
@@ -71,6 +82,14 @@ def batch_count(delta, params) -> Optional[int]:
     raise ValueError(
         "delta leaves match neither the model's shapes nor a stacked "
         "(K, ...) batch of them")
+
+
+def _write_rows(bufs, rows, slot):
+    """Each chunk's row into its buffer at ``slot``: an in-bounds update
+    (no out-of-range guard; the host validates the slot), which aliases a
+    donated buffer instead of copying it."""
+    return tuple(lax.dynamic_update_index_in_dim(b, r.astype(b.dtype), slot, 0)
+                 for b, r in zip(bufs, rows))
 
 
 def staleness_weight(staleness, mode: str = "polynomial", a: float = 0.5):
@@ -340,6 +359,8 @@ class AsyncServer:
         # per-slot presence (host metadata) — shared by every ingest path so
         # reordered / pinned-slot arrivals land correctly
         self._present = [False] * buffer_size
+        # an undonated output of each store still in flight (oldest first)
+        self._in_flight: collections.deque = collections.deque()
         self._session_base = jax.random.PRNGKey(session_seed)
         self._push_base = jax.random.PRNGKey(0xA5)
         if use_pallas is None:
@@ -406,6 +427,7 @@ class AsyncServer:
             # buffers live at the WIRE widths: under an active compression
             # spec every slot stores the compressed (sketch-domain) row
             wire = agg.plan_wire_chunks(spec, plan)
+            # donated to every store: see ``_buf``
             self._bufs = tuple(jnp.zeros((buffer_size, wc.padded), jnp.int32)
                                for wc in wire)
             self._wts = jnp.zeros((buffer_size,), jnp.float32)
@@ -446,13 +468,14 @@ class AsyncServer:
                         masked=masked, use_pallas=use_pallas, ops=ops)
                 return rows, w, nrm, clipped
 
-            @jax.jit
+            @functools.partial(jax.jit, donate_argnums=(0,))
             def _write_row(bufs, stal, wts, norms, clips, slot, rows, s, w,
                            nrm, clipped):
-                """SERVER-side jit: store one masked row (all chunks)."""
+                """SERVER-side jit: store one masked row (all chunks) in
+                place — the buffer is donated and the row written in bounds
+                (the host validated the slot), so nothing is copied."""
                 with jax.named_scope("store"):
-                    return (tuple(b.at[slot].set(r)
-                                  for b, r in zip(bufs, rows)),
+                    return (_write_rows(bufs, rows, slot),
                             stal.at[slot].set(jnp.asarray(s, jnp.float32)),
                             wts.at[slot].set(w),
                             norms.at[slot].set(nrm),
@@ -481,6 +504,7 @@ class AsyncServer:
             self._wire_pack = _wire_pack
             self._wire_unpack = _wire_unpack
         else:
+            # donated to every store: see ``_buf``
             self._bufs = tuple(
                 jnp.zeros((buffer_size, ck.padded), jnp.float32)
                 for ck in plan.chunks)
@@ -490,12 +514,12 @@ class AsyncServer:
                 staleness_exponent=staleness_exponent,
                 mask_mode=mask_mode, use_pallas=use_pallas)
 
-            @jax.jit
+            @functools.partial(jax.jit, donate_argnums=(0,))
             def _write(bufs, stal, valid, slot, delta, s):
+                """Store one raw delta's rows in place (as ``_write_row``)."""
                 with jax.named_scope("store"):
                     rows = plan.chunk_arrays(delta, pad=True)
-                    return (tuple(b.at[slot].set(r)
-                                  for b, r in zip(bufs, rows)),
+                    return (_write_rows(bufs, rows, slot),
                             stal.at[slot].set(jnp.asarray(s, jnp.float32)),
                             valid.at[slot].set(1.0))
 
@@ -510,7 +534,8 @@ class AsyncServer:
     def _buf(self):
         """The contribution buffer — bare (B, D) array under the degenerate
         single-chunk plan (the legacy view), tuple of per-chunk arrays
-        otherwise."""
+        otherwise.  The next store donates it: read it between pushes, and
+        keep no reference to it across a ``push``."""
         return self._bufs[0] if len(self._bufs) == 1 else self._bufs
 
     def _session_key(self):
@@ -683,17 +708,30 @@ class AsyncServer:
                    rng=None) -> None:
         """Write one masked row into its session slot (+ apply when full).
 
-        The ``store`` span covers the write's dispatch and the slot
-        bookkeeping, and closes before the apply (``decode``) opens."""
+        The ``store`` span covers the wait for a store in flight, the
+        write's dispatch and the slot bookkeeping, and closes before the
+        apply (``decode``) opens."""
         rows = row if isinstance(row, tuple) else (row,)
         with self._span("store", slot=slot):
+            self._bound_in_flight()
             (self._bufs, self._stal, self._wts, self._norms,
              self._clips) = self._write_row(
                 self._bufs, self._stal, self._wts, self._norms, self._clips,
                 slot, rows, staleness, w, nrm, clipped)
+            self._in_flight.append(self._stal)
             self._mark_stored(slot)
         if self._fill >= self.buffer_size:
             self._apply(rng)
+
+    def _bound_in_flight(self) -> None:
+        """Before a store: wait until fewer than ``_ROWS_IN_FLIGHT``
+        earlier stores are unfinished, counting each dispatch that had to
+        wait (``store_waits``)."""
+        while len(self._in_flight) >= _ROWS_IN_FLIGHT:
+            done = self._in_flight.popleft()
+            if not done.is_ready():
+                self.telemetry.count("store_waits", **self._tl)
+                done.block_until_ready()
 
     def _mark_stored(self, slot: int) -> None:
         self._present[slot] = True
@@ -776,9 +814,11 @@ class AsyncServer:
         if slot is None:
             slot = self._present.index(False)
         with self._span("store", slot=slot):
+            self._bound_in_flight()
             self._bufs, self._stal, self._valid = self._write(
                 self._bufs, self._stal, self._valid, slot, delta,
                 staleness)
+            self._in_flight.append(self._stal)
             self._mark_stored(slot)
         if self._fill >= self.buffer_size:
             self._apply(rng)
